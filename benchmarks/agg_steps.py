@@ -21,13 +21,14 @@ warm-up first; the warm-up durations are recorded separately as
 ``prove_compile_s`` / ``verify_compile_s`` so jit compilation never
 pollutes (or de-monotonizes) the reported numbers.
 
-``prove_compile_warm_s`` is the warm-start cost: what a FRESH process
-pays on its first prove once the serialized-executable cache
-(`repro.core.execache`) is populated.  It is measured in a controlled
-fresh subprocess (--warm-probe): the parent's cold warm-up populates
-the disk cache, then the child proves twice and reports
-first_prove - steady_prove along with the executable-cache hit/miss
-counters (a correct warm start shows ``misses == 0``).  The old
+The parent process never touches JAX (a chip belongs to one process,
+so a child could not reach it after the parent had): every cell runs in
+a child of its own.  Per T a cold child proves and measures, populating
+the serialized-executable cache (`repro.core.execache`) on disk, and
+then a warm child reports ``prove_compile_warm_s``, the warm-start
+cost: what a FRESH process pays on its first prove once that cache is
+populated, first_prove - steady_prove, along with the executable-cache
+hit/miss counters (a correct warm start shows ``misses == 0``).  The old
 in-process ``jax.clear_caches()`` + re-prove measurement is gone — it
 dropped executables a fresh process would load from disk while KEEPING
 warm host state a fresh process wouldn't have, so it could read higher
@@ -57,23 +58,20 @@ import time
 
 import numpy as np
 
-_PROBE_TAG = "WARM_PROBE_RESULT "
+_CHILD_TAG = "AGG_STEPS_CHILD "
 
 
-def _warm_probe_child(params: dict) -> None:
-    """Body of the ``--warm-probe`` subprocess: starting from a populated
-    executable-cache disk (the parent's cold warm-up wrote it), rebuild
-    the keys, prove twice, and report first/steady timings plus the
-    execache counters as one tagged JSON line on stdout.  This IS the
+def _warm_probe(params: dict) -> dict:
+    """Body of the warm child: starting from a populated executable-cache
+    disk (the cold child wrote it), rebuild the keys, prove twice, and
+    report first/steady timings plus the execache counters.  This IS the
     warm-start scenario: a fresh prover process for a config someone has
     proved before on this machine."""
     from repro.core import execache
     from repro.core.quantfc import (QuantConfig,
                                     synthetic_sgd_trajectory_widths)
     from repro.core.pipeline import PipelineConfig, ProofSession, make_keys
-    from repro.util import enable_compilation_cache
 
-    enable_compilation_cache()        # mirror what a real prover enables
     widths = tuple(params["widths"])
     cfg = PipelineConfig(n_layers=len(widths) - 1, batch=params["batch"],
                          q_bits=params["q_bits"], r_bits=params["r_bits"],
@@ -98,42 +96,62 @@ def _warm_probe_child(params: dict) -> None:
     first = prove_once(0)
     stats = execache.stats()          # counters for the FIRST prove only
     steady = min(prove_once(s) for s in (1, 2))
-    print(_PROBE_TAG + json.dumps({
+    return {
         "setup_s": setup_s,
         "first_prove_s": first,
         "steady_prove_s": steady,
         "warm_overhead_s": max(0.0, first - steady),
         "exec_stats": stats,
         "exec_warm": execache.enabled() and execache.cache_dir() is not None,
-    }), flush=True)
+    }
 
 
-def _measure_warm(T: int, batch: int, q_bits: int, r_bits: int, widths,
-                  attempts: int = 2):
-    """Run the warm-start probe in a controlled FRESH subprocess and
-    return its JSON report (best of ``attempts`` runs by warm overhead —
-    the probe is pure wall clock, so background load can only inflate
-    it).  The parent must have proved this exact config already (so the
-    executable-cache disk is populated)."""
-    params = {"T": T, "batch": batch, "q_bits": q_bits, "r_bits": r_bits,
-              "widths": list(widths)}
+def _child_main(kind: str, params: dict) -> None:
+    """Body of a ``--child`` process: run one cell and print its report
+    as one tagged JSON line on stdout."""
+    from repro.util import enable_compilation_cache
+
+    enable_compilation_cache()        # mirror what a real prover enables
+    if kind == "cold":
+        report = bench_T(**params)
+    elif kind == "warm":
+        report = _warm_probe(params)
+    elif kind == "het":
+        report = bench_heterogeneous(argparse.Namespace(**params))
+    else:
+        raise SystemExit(f"unknown child kind {kind!r}")
+    print(_CHILD_TAG + json.dumps(report), flush=True)
+
+
+def _run_child(kind: str, params: dict) -> dict:
+    """Run one cell in a fresh interpreter and return its report."""
     here = os.path.abspath(__file__)
     src = os.path.join(os.path.dirname(os.path.dirname(here)), "src")
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, here, "--child", kind, json.dumps(params)],
+        capture_output=True, text=True, env=env, timeout=1800)
+    for line in proc.stdout.splitlines():
+        if line.startswith(_CHILD_TAG):
+            return json.loads(line[len(_CHILD_TAG):])
+    raise RuntimeError(
+        f"{kind} child failed (rc={proc.returncode}):\n"
+        f"{proc.stdout[-1000:]}\n{proc.stderr[-2000:]}")
+
+
+def _measure_warm(T: int, batch: int, q_bits: int, r_bits: int, widths,
+                  attempts: int = 2):
+    """Run the warm-start probe in a FRESH child and return its report
+    (best of ``attempts`` runs by warm overhead — the probe is pure wall
+    clock, so background load can only inflate it).  A cold child must
+    have proved this exact config already (so the executable-cache disk
+    is populated)."""
+    params = {"T": T, "batch": batch, "q_bits": q_bits, "r_bits": r_bits,
+              "widths": list(widths)}
     best = None
     for _ in range(attempts):
-        proc = subprocess.run(
-            [sys.executable, here, "--warm-probe", json.dumps(params)],
-            capture_output=True, text=True, env=env, timeout=1800)
-        report = None
-        for line in proc.stdout.splitlines():
-            if line.startswith(_PROBE_TAG):
-                report = json.loads(line[len(_PROBE_TAG):])
-        if report is None:
-            raise RuntimeError(
-                f"warm probe subprocess failed (rc={proc.returncode}):\n"
-                f"{proc.stdout[-1000:]}\n{proc.stderr[-2000:]}")
+        report = _run_child("warm", params)
         # a single re-traced program anywhere disqualifies the whole
         # warm start — never let a lucky fast attempt mask it
         if report["exec_stats"]["misses"] > 0:
@@ -144,9 +162,34 @@ def _measure_warm(T: int, batch: int, q_bits: int, r_bits: int, widths,
     return best
 
 
+def bench_cell(T: int, layers: int, batch: int, width: int, q_bits: int,
+               r_bits: int, repeats: int, verify: bool,
+               warm_probe: bool = True) -> dict:
+    """One T row: the cold child's measurements, plus the warm child's
+    warm-start cost when ``warm_probe``."""
+    row = _run_child("cold", {
+        "T": T, "layers": layers, "batch": batch, "width": width,
+        "q_bits": q_bits, "r_bits": r_bits, "repeats": repeats,
+        "verify": verify})
+    warm = None
+    if warm_probe:
+        warm = _measure_warm(T, batch, q_bits, r_bits,
+                             (width,) * (layers + 1))
+    row.update({
+        "prove_compile_warm_s": warm["warm_overhead_s"] if warm else None,
+        "warm_first_prove_s": warm["first_prove_s"] if warm else None,
+        "warm_steady_prove_s": warm["steady_prove_s"] if warm else None,
+        "warm_setup_s": warm["setup_s"] if warm else None,
+        "warm_exec_stats": warm["exec_stats"] if warm else None,
+        "warm_exec_warm": warm["exec_warm"] if warm else None,
+    })
+    return row
+
+
 def bench_T(T: int, layers: int, batch: int, width: int, q_bits: int,
-            r_bits: int, repeats: int, verify: bool, widths=None,
-            warm_probe: bool = True):
+            r_bits: int, repeats: int, verify: bool, widths=None):
+    """Prove and verify one aggregated session in this process (a cold
+    child's body): compile-inclusive first prove, then best-of-N."""
     from repro.core.quantfc import (QuantConfig,
                                     synthetic_sgd_trajectory_widths)
     from repro.core.pipeline import (PipelineConfig, ProofSession,
@@ -174,17 +217,6 @@ def bench_T(T: int, layers: int, batch: int, width: int, q_bits: int,
     # the warmup duration is recorded SEPARATELY so compile time never
     # leaks into (and never jitters) the reported prove/verify numbers
     prove_compile_s, proof, _ = prove_once(0)
-
-    # warm-start cost: what a FRESH process pays on its first prove with
-    # the executable-cache disk populated (which the cold warm-up above
-    # just did).  Measured in a controlled fresh subprocess — an
-    # in-process jax.clear_caches() probe is neither cold nor warm: it
-    # drops executables a fresh process would load from disk while
-    # keeping warm host state a fresh process wouldn't have
-    prove_compile_warm_s, warm = None, None
-    if warm_probe:
-        warm = _measure_warm(T, batch, q_bits, r_bits, widths)
-        prove_compile_warm_s = warm["warm_overhead_s"]
 
     best, phases = float("inf"), None
     for rep in range(repeats):
@@ -215,12 +247,6 @@ def bench_T(T: int, layers: int, batch: int, width: int, q_bits: int,
         "proof_bytes": proof_bytes,
         "per_step_bytes": proof_bytes / T,
         "prove_compile_s": prove_compile_s,
-        "prove_compile_warm_s": prove_compile_warm_s,
-        "warm_first_prove_s": warm["first_prove_s"] if warm else None,
-        "warm_steady_prove_s": warm["steady_prove_s"] if warm else None,
-        "warm_setup_s": warm["setup_s"] if warm else None,
-        "warm_exec_stats": warm["exec_stats"] if warm else None,
-        "warm_exec_warm": warm["exec_warm"] if warm else None,
         "verify_s": verify_s,
         "verify_compile_s": verify_compile_s,
         "verify_ok": ok,
@@ -237,11 +263,10 @@ def bench_heterogeneous(args, T: int = 2):
     het_widths = tuple(int(w) for w in args.het_widths.split(","))
     uni = bench_T(T, args.het_uniform_layers, args.batch,
                   args.het_uniform_width, args.q_bits, args.r_bits,
-                  args.repeats, verify=not args.no_verify,
-                  warm_probe=False)
+                  args.repeats, verify=not args.no_verify)
     het = bench_T(T, 0, args.batch, 0, args.q_bits, args.r_bits,
                   args.repeats, verify=not args.no_verify,
-                  widths=het_widths, warm_probe=False)
+                  widths=het_widths)
     p_het = sum(a * b for a, b in zip(het_widths, het_widths[1:]))
     p_uni = args.het_uniform_layers * args.het_uniform_width ** 2
     cell = {
@@ -331,13 +356,14 @@ def main(argv=None):
     ap.add_argument("--phases-out", default=None,
                     help="per-phase prover profile JSON "
                          "(default BENCH_prover_phases.json)")
-    ap.add_argument("--warm-probe", default=None, metavar="JSON",
-                    help=argparse.SUPPRESS)   # internal: subprocess body
+    ap.add_argument("--child", nargs=2, default=None,
+                    metavar=("KIND", "JSON"),
+                    help=argparse.SUPPRESS)   # internal: one cell's body
     ap.add_argument("--no-warm-probe", action="store_true",
                     help="skip the fresh-subprocess warm-start probe")
     args = ap.parse_args(argv)
-    if args.warm_probe is not None:
-        _warm_probe_child(json.loads(args.warm_probe))
+    if args.child is not None:
+        _child_main(args.child[0], json.loads(args.child[1]))
         return None
     if args.smoke:
         # T=8 rides along so CI can gate the serialized per-step size
@@ -353,16 +379,13 @@ def main(argv=None):
     if args.phases_out is None:
         args.phases_out = None if args.smoke else "BENCH_prover_phases.json"
 
-    from repro.util import enable_compilation_cache
-    enable_compilation_cache()
-
     steps = sorted({int(s) for s in args.steps_list.split(",")})
     rows = []
     for T in steps:
-        row = bench_T(T, args.layers, args.batch, args.width,
-                      args.q_bits, args.r_bits, args.repeats,
-                      verify=not args.no_verify,
-                      warm_probe=not args.no_warm_probe)
+        row = bench_cell(T, args.layers, args.batch, args.width,
+                         args.q_bits, args.r_bits, args.repeats,
+                         verify=not args.no_verify,
+                         warm_probe=not args.no_warm_probe)
         base = rows[0] if rows else row
         row["amortization_vs_T1"] = (row["per_step_s"] / base["per_step_s"]
                                      if base["T"] == 1 else None)
@@ -387,7 +410,10 @@ def main(argv=None):
             rows, "per_step_bytes"),
     }
     if not args.no_het:
-        result["heterogeneous"] = bench_heterogeneous(args)
+        result["heterogeneous"] = _run_child("het", {
+            k: getattr(args, k) for k in (
+                "het_widths", "het_uniform_layers", "het_uniform_width",
+                "batch", "q_bits", "r_bits", "repeats", "no_verify")})
 
     phases_result = {
         "config": result["config"],

@@ -18,12 +18,15 @@ removes that cost end to end:
 
 Conventions for wrapped functions: dynamic arguments are positional jax
 arrays, static arguments are keywords (listed in ``static_argnames``).
-The cache key is (name, backend, dynamic shapes/dtypes, statics); the
-proof geometry — graph spec, quantization, aggregation window T — is
-fully encoded in the argument shapes, so `ProvingKey`s for different
-configs can never collide in the cache.  The disk directory is keyed by
-jax/jaxlib version + backend (stale entries from other versions are
-never loaded), and every load failure falls back to a fresh compile.
+The cache key is (name, backend, device kind, dynamic shapes/dtypes,
+statics); the proof geometry — graph spec, quantization, aggregation
+window T — is fully encoded in the argument shapes, so `ProvingKey`s for
+different configs can never collide in the cache.  The disk directory
+sits under the shared compile-cache root (`repro.util.cache_root`,
+overridable by ``$ZKDL_EXEC_CACHE``) and is keyed by jax/jaxlib version
++ backend + device kind (entries from another version or another chip
+generation are never loaded), and every load failure falls back to a
+fresh compile.
 
 Counters (`stats()`) make warm starts auditable: a warmed process
 reports ``misses == 0`` — the cross-process "never re-traces" contract
@@ -31,14 +34,16 @@ pinned by tests/test_exec_cache.py.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
+import re
 import threading
 
 _DISK_ENV = "ZKDL_EXEC_CACHE"          # path override; "off"/"0" disables disk
 _MODE_ENV = "ZKDL_EXEC_MODE"           # "off" disables the whole cache
-_SCHEMA = 1                            # bump to invalidate old disk layouts
+_SCHEMA = 2                            # bump to invalidate old disk entries
 
 _lock = threading.RLock()
 _registry: dict = {}
@@ -67,17 +72,37 @@ def clear() -> None:
         _registry.clear()
 
 
-def cache_dir() -> str | None:
-    """Disk directory for serialized executables (None = disk disabled)."""
+def disk_root() -> str | None:
+    """Root of the disk cache, above its version subdirectory:
+    ``$ZKDL_EXEC_CACHE`` when set (``off``/``0``/``none`` = disk
+    disabled, returns None), else ``zkdl-exec/`` under the shared
+    compile-cache root."""
     d = os.environ.get(_DISK_ENV, "")
     if d.lower() in ("off", "0", "none"):
         return None
-    if not d:
-        d = os.path.join(os.path.expanduser("~"), ".cache", "zkdl-exec")
+    if d:
+        return d
+    from repro.util import cache_root
+    return os.path.join(cache_root(), "zkdl-exec")
+
+
+@functools.cache
+def device_kind() -> str:
+    """``device_kind`` of the default device (e.g. ``TPU v5 lite``)."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def cache_dir() -> str | None:
+    """Disk directory for serialized executables (None = disk disabled)."""
+    d = disk_root()
+    if d is None:
+        return None
     import jax
     import jaxlib
+    kind = re.sub(r"[^A-Za-z0-9.]+", "_", device_kind())
     sub = (f"{jax.__version__}-{jaxlib.__version__}-"
-           f"{jax.default_backend()}-v{_SCHEMA}")
+           f"{jax.default_backend()}-{kind}-v{_SCHEMA}")
     return os.path.join(d, sub)
 
 
@@ -87,7 +112,7 @@ def _argsig(a):
 
 def _key(name: str, args, statics, pos_statics=()):
     import jax
-    return (name, jax.default_backend(),
+    return (name, jax.default_backend(), device_kind(),
             tuple(sorted(statics.items())), repr(pos_statics),
             tuple(_argsig(a) for a in args))
 
@@ -106,8 +131,14 @@ def _load_or_compile(key, jitted, args, statics):
         try:
             with open(path, "rb") as f:
                 _stored_key, payload, in_tree, out_tree = pickle.load(f)
+            import jax
             from jax.experimental import serialize_executable as se
-            comp = se.deserialize_and_load(payload, in_tree, out_tree)
+            # a wrapped program is a plain single-device jit; loaded for
+            # every local device (the default), it would expect one shard
+            # per device in a process with several (forced host devices)
+            comp = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=jax.devices()[:1])
             with _lock:
                 _registry[key] = comp
                 _stats["disk_hits"] += 1

@@ -134,6 +134,21 @@ def _split(t):
     return t & WMASK, t >> WORD
 
 
+def _stack_limbs(limbs):
+    """The (..., 4) result of one field op.
+
+    On the CPU an optimization barrier keeps XLA from fusing this op into
+    the next one: every output limb reads every input limb, and XLA:CPU
+    re-derives a fused chain's inputs per limb, so the work grows ~4x
+    with each chained op (4 chained mont_muls ran for minutes; the prover
+    chains hundreds).  The TPU compiler's cost stays linear in the chain
+    length, and a barrier there would only add HBM traffic."""
+    out = jnp.stack(limbs, axis=-1)
+    if jax.default_backend() == "cpu":
+        out = jax.lax.optimization_barrier(out)
+    return out
+
+
 def mont_mul(spec: FieldSpec, a, b):
     """CIOS Montgomery multiplication: returns a*b*2^-64 mod m (canonical).
 
@@ -180,8 +195,8 @@ def _cond_sub_mod(spec: FieldSpec, t):
         u.append(d & WMASK)
         borrow = (d >> 31)  # top bit set iff wrapped below zero
     keep_t = borrow.astype(bool)  # borrow out => t < m
-    limbs = [jnp.where(keep_t, t[j], u[j]) for j in range(NLIMB)]
-    return jnp.stack(limbs, axis=-1)
+    return _stack_limbs([jnp.where(keep_t, t[j], u[j])
+                         for j in range(NLIMB)])
 
 
 def add(spec: FieldSpec, a, b):
@@ -211,8 +226,8 @@ def sub(spec: FieldSpec, a, b):
         acc = d[j] + jnp.uint32(pl[j]) + c
         s, c = _split(acc)
         e.append(s)
-    limbs = [jnp.where(wrapped, e[j], d[j]) for j in range(NLIMB)]
-    return jnp.stack(limbs, axis=-1)
+    return _stack_limbs([jnp.where(wrapped, e[j], d[j])
+                         for j in range(NLIMB)])
 
 
 def neg(spec: FieldSpec, a):

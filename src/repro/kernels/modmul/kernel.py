@@ -39,7 +39,7 @@ def _modmul_body(a_ref, b_ref, o_ref, *, spec: FieldSpec):
                    static_argnames=("spec", "block_rows", "interpret"))
 def modmul_planes(a_planes, b_planes, *, spec: FieldSpec,
                   block_rows: int = DEFAULT_BLOCK_ROWS,
-                  interpret: bool = True):
+                  interpret: bool):
     """(4, R, 128) x (4, R, 128) -> (4, R, 128) Montgomery product."""
     nl, rows, lane = a_planes.shape
     assert nl == NLIMB and lane == LANE and b_planes.shape == a_planes.shape

@@ -73,7 +73,7 @@ def _qmatmul_body(ah_ref, ac_ref, bh_ref, bc_ref,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def qmatmul_digits(a_hi, a_c, b_hi, b_c, *,
                    bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                   bk: int = DEFAULT_BK, interpret: bool = True):
+                   bk: int = DEFAULT_BK, interpret: bool):
     """Four int8 digit matrices -> four exact int32 product matrices.
 
     a_hi/a_c: (M, K) int8;  b_hi/b_c: (K, N) int8.

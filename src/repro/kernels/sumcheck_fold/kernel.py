@@ -62,7 +62,7 @@ def _fold_halves_body(lo_ref, hi_ref, clo_ref, chi_ref, o_ref, *,
 def fold_halves_planes(lo_planes, hi_planes, clo_tile, chi_tile, *,
                        spec: FieldSpec,
                        block_rows: int = DEFAULT_BLOCK_ROWS,
-                       interpret: bool = True):
+                       interpret: bool):
     """(4,R,128) lo/hi planes + (4,1,128) coefficient tiles -> folded."""
     nl, rows, lane = lo_planes.shape
     assert nl == NLIMB and lane == LANE
@@ -131,7 +131,7 @@ def _pow_mul_body(lo_ref, hi_ref, elo_ref, ehi_ref, o_ref, *,
 def pow_mul_planes(lo_planes, hi_planes, elo_tile, ehi_tile, *,
                    spec: FieldSpec, nbits: int = 61,
                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                   interpret: bool = True):
+                   interpret: bool):
     """(4,R,128) lo/hi group-element planes + (4,1,128) standard-form
     exponent tiles -> (4,R,128) lo^{e_lo} * hi^{e_hi}."""
     nl, rows, lane = lo_planes.shape
@@ -155,7 +155,7 @@ def pow_mul_planes(lo_planes, hi_planes, elo_tile, ehi_tile, *,
                    static_argnames=("spec", "block_rows", "interpret"))
 def fold_planes(even_planes, odd_planes, r_tile, *, spec: FieldSpec,
                 block_rows: int = DEFAULT_BLOCK_ROWS,
-                interpret: bool = True):
+                interpret: bool):
     """(4,R,128) even/odd planes + (4,1,128) r tile -> (4,R,128) folded."""
     nl, rows, lane = even_planes.shape
     assert nl == NLIMB and lane == LANE

@@ -88,7 +88,7 @@ def validity_tables_planes(vals, shift, kmask, kpmask, colmask, region,
                            efull_planes, es_planes, one_tile, k_tile,
                            zm_tile, zr_tile, *, spec: FieldSpec,
                            block_rows: int = DEFAULT_BLOCK_ROWS,
-                           interpret: bool = True):
+                           interpret: bool):
     """(R,128) uint32 position planes + (4,R,128) field planes +
     (4,1,128) scalar tiles -> ((4,R,128) a, (4,R,128) b)."""
     rows, lane = vals.shape

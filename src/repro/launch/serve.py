@@ -142,7 +142,8 @@ Supervised proving
     executable cache, proves from the journal, atomically writes the
     proof, and hard-exits — signal deaths and timeouts retry, clean
     rejections don't).  Repeated failure marks the window ``FAILED``;
-    the worker moves on.
+    the worker moves on.  On a TPU only thread isolation is accepted:
+    the chip belongs to the one process that holds it.
 
 Backpressure
     ``queue_size=0`` (default) keeps the historical unbounded queue.
@@ -502,6 +503,22 @@ def recover_journal_dir(out_dir: str, T: int, manifest: Dict[int, dict],
 # Service
 # ---------------------------------------------------------------------------
 
+def _check_isolation(isolation: str) -> None:
+    """Refuse an unknown mode, and subprocess isolation on a TPU: a chip
+    belongs to one process, so a prove child started by a parent that
+    holds it could never reach the device."""
+    if isolation not in ("thread", "subprocess"):
+        raise ValueError(f"unknown isolation mode {isolation!r}")
+    if isolation == "subprocess":
+        import jax
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                "isolation='subprocess' is refused on a TPU backend: the "
+                "chip belongs to one process, and this process holds it, "
+                "so a prove child could not reach it; use "
+                "isolation='thread', the mode that works on a chip")
+
+
 class ProverService:
     """Crash-safe warm resident prover for ONE (graph, quant, T) config.
 
@@ -528,8 +545,7 @@ class ProverService:
                  injector=None):
         if backpressure not in ("block", "drop_window"):
             raise ValueError(f"unknown backpressure policy {backpressure!r}")
-        if isolation not in ("thread", "subprocess"):
-            raise ValueError(f"unknown isolation mode {isolation!r}")
+        _check_isolation(isolation)
         self.graph = graph
         self.quant = quant
         self.n_steps = n_steps
@@ -1120,8 +1136,7 @@ class ProvingGateway:
             raise ValueError("n_workers must be >= 1")
         if backpressure not in ("block", "drop_window"):
             raise ValueError(f"unknown backpressure policy {backpressure!r}")
-        if isolation not in ("thread", "subprocess"):
-            raise ValueError(f"unknown isolation mode {isolation!r}")
+        _check_isolation(isolation)
         self.out_dir = out_dir
         self.n_workers = n_workers
         self.backpressure = backpressure

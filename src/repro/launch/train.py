@@ -93,6 +93,10 @@ def run_zkdl_train(cfg, args) -> int:
     print(f"[train] zkdl {cfg.family}: {shape}, "
           f"batch {args.global_batch}, aggregating {window} step(s)/proof",
           flush=True)
+    import jax
+    devs = jax.devices()
+    print(f"[train] proving on {devs[0].platform} ({devs[0].device_kind}), "
+          f"{len(devs)} device(s)", flush=True)
 
     service = None
     if args.proof_dir:
@@ -102,7 +106,7 @@ def run_zkdl_train(cfg, args) -> int:
                                 verify=not args.no_verify)
         service.start(warm=True)
         pk, vk = service.pk, service.vk
-        print(f"[train] prover service warm in {service.warm_seconds:.1f}s "
+        print(f"[train] prover service warm in {service.warm_seconds:.3f}s "
               f"(exec cache: {service.warm_stats}); streaming proofs to "
               f"{args.proof_dir}", flush=True)
     else:
@@ -111,9 +115,7 @@ def run_zkdl_train(cfg, args) -> int:
         # bytes) is what a remote verifier would hold
         pk, vk = zk_compile(zk_cfg.graph, qc, n_steps=zk_cfg.n_steps)
     rng = np.random.default_rng(0)
-    ws = [quantfc.quantize(
-        rng.uniform(-1, 1, (widths[l], widths[l + 1])) * 0.3, qc)
-        for l in range(zk_cfg.n_layers)]
+    ws = quantfc.init_weights(rng, widths, qc)
     data_x = rng.uniform(-1, 1, (args.global_batch * 8, widths[0]))
     data_y = rng.uniform(-1, 1, (args.global_batch * 8, widths[-1]))
 

@@ -31,11 +31,6 @@ from repro.distributed import hints
 from repro.models.config import ModelConfig
 from repro.models.layers import activation, dense_init
 
-# jax.shard_map is top-level only from jax 0.5; fall back to experimental
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def init_moe(key, cfg: ModelConfig) -> Dict:
     d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
@@ -121,7 +116,7 @@ def moe_ffn(params: Dict, x, cfg: ModelConfig):
                 xf_l, rw, e, k, cap_loc)
             return buf_l, st, sg, keep, slot
 
-        buf, st, sg, keep, slot = _shard_map(
+        buf, st, sg, keep, slot = jax.shard_map(
             dispatch_shard, mesh=mesh,
             in_specs=(P(bax), P()),
             out_specs=(P(None, bax), P(bax), P(bax), P(bax), P(bax)),
@@ -163,7 +158,7 @@ def moe_ffn(params: Dict, x, cfg: ModelConfig):
                 y_l = jnp.zeros((n_loc, d), x.dtype).at[st_l].add(contrib)
                 return jax.lax.psum(y_l, model_ax)
 
-            y = _shard_map(
+            y = jax.shard_map(
                 combine_shard, mesh=mesh,
                 in_specs=(P(model_ax, bax, None), P(bax), P(bax), P(bax),
                           P(bax)),
@@ -174,7 +169,7 @@ def moe_ffn(params: Dict, x, cfg: ModelConfig):
                 return _combine(out_l, (st_l, sg_l, keep_l, slot_l), n_loc,
                                 cap_loc, x.dtype)
 
-            y = _shard_map(
+            y = jax.shard_map(
                 combine_shard, mesh=mesh,
                 in_specs=(P(None, bax), P(bax), P(bax), P(bax), P(bax)),
                 out_specs=P(bax),

@@ -29,12 +29,16 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
 
 
-def _run_child(code: str, cache_dir: str) -> dict:
+def _run_child(code: str, cache_dir: str, xla_flags: str | None = None
+               ) -> dict:
     """Run ``code`` in a fresh interpreter with the exec cache pointed
-    at ``cache_dir``; the child must print one JSON object on stdout."""
+    at ``cache_dir`` (and ``XLA_FLAGS`` set to ``xla_flags`` when given);
+    the child must print one JSON object on stdout."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["ZKDL_EXEC_CACHE"] = cache_dir
+    if xla_flags is not None:
+        env["XLA_FLAGS"] = xla_flags
     env.pop("ZKDL_EXEC_MODE", None)
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, env=env,
@@ -174,6 +178,30 @@ def test_disk_roundtrip_into_fresh_process(tmp_path):
     assert b["stats"]["disk_hits"] == 1
 
 
+def test_disk_entry_loads_in_multi_device_process(tmp_path):
+    """A wrapped program is a single-device jit: written by a one-device
+    process, it must load and run in a process with several devices
+    (forced host devices), not expect one argument shard per device."""
+    code = """
+    import json
+    import jax
+    import jax.numpy as jnp
+    from repro.core import execache
+    fn = execache.wrap("t_multidev", lambda x: x * 3 + 1)
+    execache.reset_stats()
+    out = [int(v) for v in fn(jnp.arange(4, dtype=jnp.int32))]
+    print(json.dumps({"out": out, "n_dev": len(jax.devices()),
+                      "stats": execache.stats()}))
+    """
+    a = _run_child(code, str(tmp_path), xla_flags="")
+    assert a["n_dev"] == 1 and a["stats"]["disk_writes"] == 1
+    b = _run_child(code, str(tmp_path),
+                   xla_flags="--xla_force_host_platform_device_count=4")
+    assert b["n_dev"] == 4
+    assert b["out"] == [1, 4, 7, 10]
+    assert b["stats"]["disk_hits"] == 1 and b["stats"]["misses"] == 0
+
+
 # ---------------------------------------------------------------------------
 # Integration: cross-process warm prover start
 # ---------------------------------------------------------------------------
@@ -217,8 +245,7 @@ def test_cross_process_warm_start():
 
     if not (execache.enabled() and execache.cache_dir() is not None):
         pytest.skip("executable disk cache disabled in this environment")
-    env_dir = os.environ.get("ZKDL_EXEC_CACHE") or os.path.join(
-        os.path.expanduser("~"), ".cache", "zkdl-exec")
+    env_dir = execache.disk_root()
 
     # process A: prove once (fills any disk gaps for this geometry)
     a = _run_child(_PROVE_CHILD, env_dir)
