@@ -403,12 +403,16 @@ def group_gen():
 
 def decode_group(a) -> int:
     """Group element -> canonical python int (for transcripts/serialization)."""
+    from repro.core.pipeline import profile   # the pipeline imports us
+    profile.host_read()
     std = np.asarray(from_mont(FP, jnp.asarray(a)))
     return int(limbs_to_ints(std)[()])
 
 
 def decode_group_many(a) -> list:
     """(R, 4) group elements -> list of R python ints, ONE host transfer."""
+    from repro.core.pipeline import profile   # the pipeline imports us
+    profile.host_read()
     std = np.asarray(from_mont(FP, jnp.asarray(a)))
     return [int(v) for v in limbs_to_ints(std)]
 

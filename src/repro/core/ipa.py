@@ -76,12 +76,6 @@ def set_round_mode(name: str | None) -> None:
     _ipa_mode_override = name
 
 
-def _sub(prof, name: str):
-    """Sub-phase context of an optional `PhaseProfile` (else a no-op)."""
-    from repro.core.pipeline.profile import subphase
-    return subphase(prof, name)
-
-
 @dataclasses.dataclass
 class IpaProof:
     ls: List[int]
@@ -485,8 +479,8 @@ def _pair_rounds_ladder(st, transcript: Transcript,
 # ---------------------------------------------------------------------------
 
 def open_prove(key, a_mont, b_mont, blind: int, claim: int,
-               transcript: Transcript, rng: np.random.Generator,
-               prof=None) -> IpaProof:
+               transcript: Transcript, rng: np.random.Generator) -> IpaProof:
+    from repro.core.pipeline import profile   # the pipeline imports us
     n = a_mont.shape[0]
     assert n & (n - 1) == 0 and b_mont.shape[0] == n
     gens = key.gens[:n]
@@ -496,7 +490,7 @@ def open_prove(key, a_mont, b_mont, blind: int, claim: int,
 
     a, b, rho = a_mont, b_mont, int(blind)
     ls, rs = [], []
-    with _sub(prof, "ipa-rounds"):
+    with profile.span("ipa-rounds"):
         while n > 1:
             n2 = n // 2
             rho_l = int(rng.integers(0, Q, dtype=np.uint64)) % Q
@@ -513,7 +507,7 @@ def open_prove(key, a_mont, b_mont, blind: int, claim: int,
             rho = (al * al % Q * rho_l + rho + ali * ali % Q * rho_r) % Q
             n = n2
 
-    with _sub(prof, "sigma"):
+    with profile.span("sigma"):
         # final Schnorr opening of P_f = base^a h^rho, base = g_f up^{b_f}
         a_f, b_f = (int(v) for v in decode(FQ, jnp.stack([a[0], b[0]])))
         s = int(rng.integers(0, Q, dtype=np.uint64)) % Q
@@ -577,8 +571,7 @@ def open_verify(key, com, b_mont, claim: int, proof: IpaProof,
 # ---------------------------------------------------------------------------
 
 def pair_prove_many(stmts, transcript: Transcript,
-                    rng: np.random.Generator,
-                    prof=None) -> List[IpaProof]:
+                    rng: np.random.Generator) -> List[IpaProof]:
     """Prove S pair statements with interleaved rounds.
 
     ``stmts`` is a list of ``(g_gens, h_gens, h_blind, a_mont, b_mont,
@@ -589,9 +582,9 @@ def pair_prove_many(stmts, transcript: Transcript,
     then runs `_pair_round_lr_w` / `_pair_fold_first` without ever
     materializing H', bit-identically to the explicit path.  Transcript
     order per round: each active statement's (L, R) is absorbed and its
-    alpha drawn, statement by statement in list order.  ``prof`` is an
-    optional `PhaseProfile`: rounds book under the "ipa-rounds"
-    sub-phase, the sigma finales under "sigma"."""
+    alpha drawn, statement by statement in list order.  Rounds book
+    under the "ipa-rounds" span, the sigma finales under "sigma"."""
+    from repro.core.pipeline import profile   # the pipeline imports us
     states = []
     for stmt in stmts:
         gg, hh, hb, a, b, blind, claim = stmt[:7]
@@ -616,7 +609,7 @@ def pair_prove_many(stmts, transcript: Transcript,
     # run the masked size-ladder rounds: O(1) compiled bodies instead of
     # 2 per halving shape, bit-identical transcripts (see above)
     ladder = len(states) == 1 and round_mode() == "ladder"
-    with _sub(prof, "ipa-rounds"):
+    with profile.span("ipa-rounds"):
         if ladder:
             _pair_rounds_ladder(states[0], transcript, rng)
         while any(st["n"] > 1 for st in states):
@@ -661,7 +654,7 @@ def pair_prove_many(stmts, transcript: Transcript,
                 st["rho"] = (al2 * rho_l + st["rho"] + ali2 * rho_r) % Q
                 st["n"] //= 2
 
-    with _sub(prof, "sigma"):
+    with profile.span("sigma"):
         # apply the deferred outer exponents to the two surviving
         # generators of every statement in ONE batched g_pow (a gam of 1
         # — no rounds, or already materialized — is an exact no-op)

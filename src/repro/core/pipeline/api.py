@@ -29,6 +29,7 @@ import functools
 from typing import Optional, Tuple
 
 from repro.core.quantfc import QuantConfig
+from repro.core.pipeline import profile
 from repro.core.pipeline.config import (PipelineConfig, PipelineKeys,
                                         make_keys)
 from repro.core.pipeline.graph import LayerGraph
@@ -74,14 +75,17 @@ class ProvingKey:
         before = execache.stats()
         cfg = self.cfg
         quant = QuantConfig(q_bits=cfg.q_bits, r_bits=cfg.r_bits)
-        wits = synthetic_sgd_trajectory_widths(
-            cfg.n_steps, graph_widths(cfg.graph), cfg.batch, quant,
-            seed=seed, skips=graph_skips(cfg.graph))
-        session = ProofSession(self, np.random.default_rng(seed))
-        for wit in wits:
-            session.add_step(wit)
-        proof = session.prove()
-        assert session.verify(proof), "warm-up proof rejected"
+        with profile.span("warm"):
+            wits = synthetic_sgd_trajectory_widths(
+                cfg.n_steps, graph_widths(cfg.graph), cfg.batch, quant,
+                seed=seed, skips=graph_skips(cfg.graph))
+            session = ProofSession(self, np.random.default_rng(seed))
+            for wit in wits:
+                session.add_step(wit)
+            proof = session.prove()
+            with profile.span("warm-verify"):
+                ok = session.verify(proof)
+        assert ok, "warm-up proof rejected"
         after = execache.stats()
         return {k: after[k] - before[k] for k in after}
 
@@ -145,7 +149,8 @@ def compile(graph: LayerGraph, quant: Optional[QuantConfig] = None,
     quant = quant if quant is not None else QuantConfig()
     cfg = PipelineConfig.from_graph(graph, q_bits=quant.q_bits,
                                     r_bits=quant.r_bits, n_steps=n_steps)
-    keys = make_keys(cfg)
+    with profile.span("keygen"):
+        keys = make_keys(cfg)
     pk = ProvingKey(keys=keys)
     if warm:
         pk.warm()

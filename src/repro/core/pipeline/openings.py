@@ -49,7 +49,7 @@ from repro.core.mle import (enc, enc_vec, expand_point, fdot, fdot_many,
                             hexpand_point, weighted_sum)
 from repro.core.transcript import Transcript
 from repro.core.pipeline import matmul
-from repro.core.pipeline import profile as profile_mod
+from repro.core.pipeline import profile
 from repro.core.pipeline.anchor import output_gz_points
 from repro.core.pipeline.challenges import (ChallengeSchedule, WeightDraws,
                                             instance_slices, pad_point,
@@ -297,10 +297,6 @@ def stacked_witness(cfg: PipelineConfig,
                              for name, _, _ in cfg.agg_blocks])
 
 
-def _sub(prof, name: str):
-    return profile_mod.subphase(prof, name)
-
-
 def prover_blocks(cfg: PipelineConfig, tabs: FieldTables,
                   blinds: Dict[str, int], x_blinds: List[int],
                   ch: ChallengeSchedule, mat: matmul.MatmulOut, anc,
@@ -401,24 +397,28 @@ def prove(cfg: PipelineConfig, keys: PipelineKeys, tabs: FieldTables,
           blinds: Dict[str, int], x_blinds: List[int],
           aux_bits: zkrelu.AuxBits, vblinds, ch: ChallengeSchedule,
           mat: matmul.MatmulOut, anc, op: Dict[str, int],
-          e_pi1, e_pi2, e_pi3, t: Transcript, rng, prof=None):
+          e_pi1, e_pi2, e_pi3, t: Transcript, rng):
     """Runs the whole of step (c) prover-side; returns the single merged
     pair-IPA proof covering every opening block AND both zkReLU validity
-    statements.  ``prof`` (a `PhaseProfile`) attributes the sub-phases
-    claim-combine / zkrelu-validity / ipa-rounds / sigma."""
-    with _sub(prof, "claim-combine"):
+    statements, under the sub-phase spans claim-combine /
+    zkrelu-validity / zkrelu-inverse / ipa-rounds / sigma."""
+    with profile.span("claim-combine"):
         blocks, (u_relu, v, v_q1, v_r) = prover_blocks(
             cfg, tabs, blinds, x_blinds, ch, mat, anc, op,
             e_pi1, e_pi2, e_pi3, t)
 
-    with _sub(prof, "zkrelu-validity"):
+    with profile.span("zkrelu-validity"):
         # validity challenges draw BEFORE rho/agg; the a/b tables for
         # both statements come out of one validity_tables dispatch
         st = zkrelu.prove_statements(keys.validity, aux_bits, vblinds,
                                      u_relu, v, v_q1, v_r, t)
         jax.block_until_ready((st.a_main, st.b_main, st.a_rem, st.b_rem))
+    with profile.span("zkrelu-inverse"):
+        # the H-basis weights' batch inversions, dispatched after the
+        # tables: the device runs programs in order, so they book here
+        jax.block_until_ready((st.w_main, st.w_rem))
 
-    with _sub(prof, "claim-combine"):
+    with profile.span("claim-combine"):
         b_agg, claim_agg, rho = direct_sum(cfg, t, blocks)
         a_agg = stacked_witness(cfg, blocks)
         blind_agg = sum(blk.blind for blk in blocks.values()) % Q_MOD
@@ -448,7 +448,7 @@ def prove(cfg: PipelineConfig, keys: PipelineKeys, tabs: FieldTables,
         hh = group.g_pow(keys.h_merged, from_mont(FQ, w))
         stmt = (keys.g_merged, hh, keys.validity.h_blind, a_hat, b_hat,
                 blind, claim)
-    (ipa_agg,) = ipa.pair_prove_many([stmt], t, rng, prof=prof)
+    (ipa_agg,) = ipa.pair_prove_many([stmt], t, rng)
     return ipa_agg
 
 

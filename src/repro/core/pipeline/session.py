@@ -13,7 +13,6 @@ the heterogeneous pyramid cell).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -27,6 +26,7 @@ from repro.core.pipeline import matmul as matmul_mod
 from repro.core.pipeline import openings as openings_mod
 from repro.core.pipeline.challenges import ChallengeSchedule
 from repro.core.pipeline.config import PipelineConfig, PipelineKeys
+from repro.core.pipeline import profile
 from repro.core.pipeline.profile import PhaseProfile
 from repro.core.pipeline.tables import enc_tensor, rand_scalar
 from repro.core.pipeline.witness import (StackedWitness, build_field_tables,
@@ -110,16 +110,14 @@ def _as_pipeline_keys(keys) -> PipelineKeys:
 class SessionProver:
     """Two-phase prover over a stacked witness: commit, then prove."""
 
-    def __init__(self, keys, rng: np.random.Generator,
-                 profile: Optional[PhaseProfile] = None):
+    def __init__(self, keys, rng: np.random.Generator):
         self.keys = _as_pipeline_keys(keys)
         self.cfg = self.keys.cfg
         self.rng = rng
-        self.profile = profile if profile is not None else PhaseProfile()
 
     # -- commitment phase --------------------------------------------------
     def commit(self, sw: StackedWitness) -> SessionCommitments:
-        with self.profile.phase("commit"):
+        with profile.span("commit"):
             return self._commit(sw)
 
     def _commit(self, sw: StackedWitness) -> SessionCommitments:
@@ -163,24 +161,23 @@ class SessionProver:
     # -- interactive phase (Fiat-Shamir) -----------------------------------
     def prove(self, transcript: Transcript) -> AggregatedProof:
         cfg, keys, rng = self.cfg, self.keys, self.rng
-        prof = self.profile
         t = transcript
-        with prof.phase("challenges"):
+        with profile.span("challenges"):
             t.absorb_ints(b"coms", self.coms.as_ints())
             ch = ChallengeSchedule.draw(t, cfg)
 
             op: Dict[str, int] = {}
             e_pi1, e_pi2, e_pi3 = openings_mod.initial_claims(
                 cfg, self.tabs, ch, op, t)
-        with prof.phase("matmul"):
+        with profile.span("matmul"):
             mat = matmul_mod.prove(cfg, self.tabs, ch, t)        # step (a)
-        with prof.phase("anchor"):
+        with profile.span("anchor"):
             anc = anchor_mod.prove(cfg, self.tabs, ch, mat, t)   # step (b)
-        with prof.phase("openings"):
+        with profile.span("openings"):
             ipa_agg = openings_mod.prove(                        # step (c)
                 cfg, keys, self.tabs, self.blinds, self.x_blinds,
                 self.aux_bits, self.vblinds, ch, mat, anc, op,
-                e_pi1, e_pi2, e_pi3, t, rng, prof=prof)
+                e_pi1, e_pi2, e_pi3, t, rng)
 
         return AggregatedProof(
             coms=self.coms, openings=op,
@@ -208,7 +205,7 @@ class ProofSession:
         self.rng = rng if rng is not None else np.random.default_rng()
         self.label = label
         self._steps: List[StepWitness] = []
-        #: per-phase wall-clock profile of the most recent prove() call
+        #: the span records of the most recent prove() call
         self.last_profile: Optional[PhaseProfile] = None
 
     @property
@@ -231,13 +228,12 @@ class ProofSession:
     def prove(self) -> AggregatedProof:
         """Stack the queued witnesses and emit the aggregated proof."""
         prof = PhaseProfile()
-        t0 = time.perf_counter()
-        with prof.phase("stack"):
-            sw = stack_witnesses(self._steps, self.cfg)
-        prover = SessionProver(self.keys, self.rng, profile=prof)
-        prover.commit(sw)
-        proof = prover.prove(Transcript(self.label))
-        prof.total_s = time.perf_counter() - t0
+        with prof.activate(), profile.span("prove"):
+            with profile.span("stack"):
+                sw = stack_witnesses(self._steps, self.cfg)
+            prover = SessionProver(self.keys, self.rng)
+            prover.commit(sw)
+            proof = prover.prove(Transcript(self.label))
         self.last_profile = prof
         return proof
 

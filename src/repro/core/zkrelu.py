@@ -366,13 +366,16 @@ def prove_statements(keys: ValidityKeys, bits: AuxBits,
     v_k = (v - k * pow(2, qb - 1, Q_MOD) % Q_MOD
            * ((1 - upp) % Q_MOD) % Q_MOD * v_q1) % Q_MOD
     vp_k = _vp_k(k, u_relu, u_bit, qb)
+    a_main, b_main, a_rem, b_rem = (a[:n_main], b[:n_main], a[n_main:],
+                                    b[n_main:])
+    # the inversions dispatch after every table: the device runs
+    # programs in order, so a wait on the tables never waits on them
+    w_main, w_rem = batch_inv(FQ, e_full_m), batch_inv(FQ, e_full_r)
     return ValidityStatements(
-        a_main=a[:n_main], b_main=b[:n_main],
-        w_main=batch_inv(FQ, e_full_m),
+        a_main=a_main, b_main=b_main, w_main=w_main,
         claim_main=_main_claim(v_k, vp_k, z),
         blind_main=(blinds.r + k * (blinds.rq1 + blinds.rq1p)) % Q_MOD,
-        a_rem=a[n_main:], b_rem=b[n_main:],
-        w_rem=batch_inv(FQ, e_full_r),
+        a_rem=a_rem, b_rem=b_rem, w_rem=w_rem,
         claim_rem=_main_claim(v_r, 1, z_r, s_sum=(1 << rb) - 1),
         blind_rem=blinds.rr)
 

@@ -342,6 +342,8 @@ def encode_ints(spec: FieldSpec, xs) -> np.ndarray:
 
 def decode(spec: FieldSpec, a) -> np.ndarray:
     """Montgomery limb array -> object array of canonical python ints (host)."""
+    from repro.core.pipeline import profile   # the pipeline imports us
+    profile.host_read()
     std = np.asarray(from_mont(spec, jnp.asarray(a)))
     return limbs_to_ints(std)
 

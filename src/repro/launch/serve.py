@@ -565,6 +565,13 @@ class ProverService:
         self.pk = None
         self.vk = None
         self.proofs: List[Tuple[int, str, int, float]] = []
+        #: committed window -> its span records (`pipeline.profile`):
+        #: the prove's phases, then `encode` and `persist`.  Thread
+        #: isolation only: a subprocess prove records none here.
+        self.window_profiles: Dict[int, List[dict]] = {}
+        #: span records of `start(warm=True)`: `keygen`, then `warm`
+        #: (the warm-up `prove` and `warm-verify`)
+        self.warm_profile: Optional[List[dict]] = None
         self.warm_stats: Optional[dict] = None
         self.warm_seconds: float = 0.0
         self.stats = {"submitted": 0, "journaled": 0, "replayed": 0,
@@ -589,6 +596,7 @@ class ProverService:
         and launch the proving worker."""
         from repro.core import execache
         from repro.core.pipeline import compile as zk_compile
+        from repro.core.pipeline.profile import PhaseProfile
         from repro.train.checkpoint import atomic_write_bytes
 
         if self._closed:
@@ -603,13 +611,18 @@ class ProverService:
                     > self.compact_threshold):
                 compact_manifest(self.out_dir)
             t0 = time.perf_counter()
-            self.pk, self.vk = zk_compile(self.graph, self.quant,
-                                          n_steps=self.n_steps)
+            prof = PhaseProfile(window="warm")
+            with prof.activate():
+                self.pk, self.vk = zk_compile(self.graph, self.quant,
+                                              n_steps=self.n_steps)
+                if warm:
+                    before = execache.stats()
+                    self.pk.warm(seed=self.rng_seed)
+                    after = execache.stats()
+                    self.warm_stats = {k: after[k] - before[k]
+                                       for k in after}
             if warm:
-                before = execache.stats()
-                self.pk.warm(seed=self.rng_seed)
-                after = execache.stats()
-                self.warm_stats = {k: after[k] - before[k] for k in after}
+                self.warm_profile = prof.records
             self.warm_seconds = time.perf_counter() - t0
             atomic_write_bytes(os.path.join(self.out_dir, "vk.bin"),
                                self.vk.to_bytes())
@@ -863,7 +876,20 @@ class ProverService:
         return os.path.join(self.out_dir, f"proof_{window:06d}.bin")
 
     def _prove_window(self, window: int, wits) -> None:
-        from repro.core.pipeline import ProofSession, encode_proof
+        from repro.core.pipeline.profile import PhaseProfile
+
+        prof = PhaseProfile(window=window)
+        with prof.activate():
+            entry = self._prove_and_commit(window, wits)
+        if entry is not None:
+            if self.isolation == "thread":
+                self.window_profiles[window] = prof.records
+            self.proofs.append(entry)
+
+    def _prove_and_commit(self, window: int, wits):
+        """Prove, write and commit one window; its `proofs` entry once
+        committed, else None."""
+        from repro.core.pipeline import ProofSession, encode_proof, profile
         from repro.train.checkpoint import atomic_write_bytes
 
         if self.injector is not None:
@@ -894,7 +920,8 @@ class ProverService:
                 proof = session.prove()
                 if self.verify and not session.verify(proof):
                     raise RuntimeError(f"window {window}: proof REJECTED")
-                return encode_proof(proof)
+                with profile.span("encode"):
+                    return encode_proof(proof)
 
             res = supervise.run_supervised(
                 attempt, max_attempts=self.max_attempts,
@@ -909,48 +936,50 @@ class ProverService:
             self._manifest_append_safe({"window": window, "status": FAILED,
                                         "error": error,
                                         "attempts": res.n_attempts})
-            return
-        if self.isolation != "subprocess":
-            from repro.train.checkpoint import StorageError
-            try:
-                if self.injector is not None:
-                    self.injector.fire("storage/proof")
-                atomic_write_bytes(path, data)
-            except StorageError as exc:
-                # disk full at the proof write: the window FAILS (its
-                # journal is retained for a restart with free space) and
-                # the worker loop keeps serving the next window
-                self.stats["storage_errors"] += 1
-                self.stats["failed_windows"] += 1
-                self._manifest_append_safe(
-                    {"window": window, "status": FAILED,
-                     "reason": "storage", "error": str(exc)})
-                return
-        if self.injector is not None:
-            self.injector.fire("commit/pre-manifest")
-        dt = time.perf_counter() - t0
-        batch = self.pk.keys.cfg.batch
-        committed = self._manifest_append_safe(
-            {"window": window, "status": COMMITTED,
-             "n_steps": self.n_steps, "bytes": len(data),
-             # global sample-index range [start, count]
-             # of the window's per-sample commitments —
-             # the membership audit (repro.audit) binds
-             # these into the dataset root
-             "samples": [window * self.n_steps * batch,
-                         self.n_steps * batch],
-             "prove_s": round(dt, 4),
-             "attempts": res.n_attempts})
-        if not committed:
-            # proof bytes are durable but the commit line is not: leave
-            # the journal in place so a restart re-proves and commits —
-            # NEVER GC ahead of the manifest
-            return
-        if self.journal:
-            journal_gc(journal_dir(self.out_dir),
-                       window * self.n_steps, (window + 1) * self.n_steps)
+            return None
+        with profile.span("persist"):
+            if self.isolation != "subprocess":
+                from repro.train.checkpoint import StorageError
+                try:
+                    if self.injector is not None:
+                        self.injector.fire("storage/proof")
+                    atomic_write_bytes(path, data)
+                except StorageError as exc:
+                    # disk full at the proof write: the window FAILS
+                    # (its journal is retained for a restart with free
+                    # space) and the worker loop keeps serving the next
+                    # window
+                    self.stats["storage_errors"] += 1
+                    self.stats["failed_windows"] += 1
+                    self._manifest_append_safe(
+                        {"window": window, "status": FAILED,
+                         "reason": "storage", "error": str(exc)})
+                    return None
+            if self.injector is not None:
+                self.injector.fire("commit/pre-manifest")
+            dt = time.perf_counter() - t0
+            batch = self.pk.keys.cfg.batch
+            committed = self._manifest_append_safe(
+                {"window": window, "status": COMMITTED,
+                 "n_steps": self.n_steps, "bytes": len(data),
+                 # global sample-index range [start, count]
+                 # of the window's per-sample commitments —
+                 # the membership audit (repro.audit) binds
+                 # these into the dataset root
+                 "samples": [window * self.n_steps * batch,
+                             self.n_steps * batch],
+                 "prove_s": round(dt, 4),
+                 "attempts": res.n_attempts})
+            if not committed:
+                # proof bytes are durable but the commit line is not:
+                # leave the journal in place so a restart re-proves and
+                # commits — NEVER GC ahead of the manifest
+                return None
+            if self.journal:
+                journal_gc(journal_dir(self.out_dir), window * self.n_steps,
+                           (window + 1) * self.n_steps)
         self.stats["proved"] += 1
-        self.proofs.append((window, path, len(data), dt))
+        return (window, path, len(data), dt)
 
     def _child_argv(self, window: int) -> List[str]:
         argv = [sys.executable, "-m", "repro.launch.serve",

@@ -55,12 +55,14 @@ def build_zkdl_step(zk_cfg, lr_shift: int = 8):
     Returns ``step(ws, batch) -> (new_ws, StepWitness)`` with batch a
     dict of int64 arrays {"x": (B, d_0), "y": (B, d_L)} at scale 2^R."""
     from repro.core import quantfc
+    from repro.core.pipeline import profile
 
     qc = quantfc.QuantConfig(q_bits=zk_cfg.q_bits, r_bits=zk_cfg.r_bits)
 
     def step(ws, batch):
-        wit = quantfc.train_step_witness(batch["x"], batch["y"], ws, qc)
-        return quantfc.sgd_apply(ws, wit.gw, lr_shift, qc), wit
+        with profile.span("train-step"):
+            wit = quantfc.train_step_witness(batch["x"], batch["y"], ws, qc)
+            return quantfc.sgd_apply(ws, wit.gw, lr_shift, qc), wit
 
     return step
 

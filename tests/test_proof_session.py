@@ -387,3 +387,110 @@ def test_prover_phase_profile_accounts_for_total(keys):
                                   "matmul", "anchor", "openings"}
     assert prof.accounted_s <= prof.total_s * 1.001 + 1e-6
     assert prof.accounted_s >= prof.total_s * 0.9
+
+
+@pytest.fixture(scope="module")
+def profiled(keys):
+    """The span records of one prove."""
+    session = ProofSession(keys, np.random.default_rng(35))
+    for w in make_step_witnesses(seed=35):
+        session.add_step(w)
+    session.prove()
+    return session.last_profile
+
+
+def test_prove_records_every_phase_and_sub_phase(profiled):
+    """`prove` holds every phase; `openings` holds every sub-phase, the
+    inversion's wait right after the validity tables; one window id."""
+    from repro.core.pipeline.profile import PHASES, SUB_PHASES
+
+    recs = profiled.records
+    by_name = {}
+    for r in recs:
+        by_name.setdefault(r["name"], []).append(r)
+    assert set(by_name) == {"prove", *PHASES, *SUB_PHASES}
+    (top,) = by_name["prove"]
+    assert top["parent"] is None
+    for names, parent in ((PHASES, "prove"), (SUB_PHASES, "openings")):
+        for name in names:
+            for r in by_name[name]:
+                assert recs[r["parent"]]["name"] == parent, name
+    assert len(by_name["claim-combine"]) == 2
+    (valid,), (inverse,) = by_name["zkrelu-validity"], by_name["zkrelu-inverse"]
+    assert valid["end"] <= inverse["start"]
+    assert {r["window"] for r in recs} == {top["window"]}
+
+
+def test_no_span_self_time_is_negative(profiled):
+    recs = profiled.records
+    inner = [0.0] * len(recs)
+    for r in recs:
+        if r["parent"] is not None:
+            inner[r["parent"]] += r["end"] - r["start"]
+    for r, s in zip(recs, inner):
+        assert r["end"] - r["start"] - s >= -1e-9, r["name"]
+
+
+def test_host_reads_count_every_decode_and_follow_the_geometry(keys):
+    """Every call into the three decode functions books one host read,
+    and two proves of one geometry read the same number of times."""
+    import sys
+
+    from repro.core import group
+    from repro.field import modarith
+
+    codes = {modarith.decode.__code__, group.decode_group.__code__,
+             group.decode_group_many.__code__}
+    calls = []
+
+    def hook(frame, event, arg):
+        if event == "call" and frame.f_code in codes:
+            calls.append(frame.f_code.co_name)
+
+    reads = []
+    for seed in (36, 37):
+        session = ProofSession(keys, np.random.default_rng(seed))
+        for w in make_step_witnesses(seed=seed):
+            session.add_step(w)
+        calls.clear()
+        sys.setprofile(hook)
+        try:
+            session.prove()
+        finally:
+            sys.setprofile(None)
+        reads.append(sum(r["host_reads"]
+                         for r in session.last_profile.records))
+        assert reads[-1] == len(calls) > 0
+    assert reads[0] == reads[1]
+
+
+def test_prover_service_keeps_each_committed_windows_spans(tmp_path):
+    """Thread isolation: one `window_profiles` entry per committed
+    window (the prove, `encode`, `persist`, all under the window's id),
+    and the warm-up's records in `warm_profile`."""
+    from repro.core.pipeline import build_fcnn_graph
+    from repro.launch import serve
+    from repro.launch.serve import ProverService
+
+    widths, T = (4, 4, 4), 2
+    service = ProverService(build_fcnn_graph(widths, batch=2), QC,
+                            n_steps=T, out_dir=str(tmp_path), rng_seed=5)
+    service.start(warm=True)
+    for wit in synthetic_sgd_trajectory_widths(2 * T, widths, 2, QC,
+                                               seed=5):
+        service.submit(wit)
+    service.close()
+    committed = {w for w, rec in serve.read_manifest(str(tmp_path)).items()
+                 if rec["status"] == serve.COMMITTED}
+    assert committed == {0, 1} == set(service.window_profiles)
+    for w, recs in service.window_profiles.items():
+        top = [r["name"] for r in recs if r["parent"] is None]
+        assert top == ["prove", "encode", "persist"]
+        assert {r["window"] for r in recs} == {w}
+    warm = service.warm_profile
+    names = [r["name"] for r in warm]
+    assert names[0] == "keygen" and "warm-verify" in names
+    (i_warm,) = [i for i, r in enumerate(warm) if r["name"] == "warm"]
+    for name in ("prove", "warm-verify"):
+        (r,) = [r for r in warm if r["name"] == name]
+        assert r["parent"] == i_warm
