@@ -52,10 +52,10 @@ PHASES = ("stack", "commit", "challenges", "matmul", "anchor", "openings")
 # per-tensor rho folds, the direct-sum assembly AND the merged-vector
 # concatenation), the zkReLU validity statement/table preparation
 # (challenge draws + the Pallas/jnp table kernel), the wait for the
-# validity statements' H-basis inversions (`batch_inv`), the merged
-# pair-IPA's L/R round loop and its final Schnorr opening.  Tracked
-# apart from `phases_s` so `accounted_s` (which the --smoke attribution
-# check compares against total_s) never double counts.
+# validity statements' H-basis inversions (`batch_inv`, lane-blocked),
+# the merged pair-IPA's L/R round loop and its final Schnorr opening.
+# Tracked apart from `phases_s` so `accounted_s` (which the --smoke
+# attribution check compares against total_s) never double counts.
 SUB_PHASES = ("claim-combine", "zkrelu-validity", "zkrelu-inverse",
               "ipa-rounds", "sigma")
 

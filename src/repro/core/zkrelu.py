@@ -368,8 +368,10 @@ def prove_statements(keys: ValidityKeys, bits: AuxBits,
     vp_k = _vp_k(k, u_relu, u_bit, qb)
     a_main, b_main, a_rem, b_rem = (a[:n_main], b[:n_main], a[n_main:],
                                     b[n_main:])
-    # the inversions dispatch after every table: the device runs
-    # programs in order, so a wait on the tables never waits on them
+    # the H-basis weights 1/e: two lane-blocked batch inversions (a
+    # chain of about n / `_INV_LANES` steps each), dispatched after
+    # every table: the device runs programs in order, so a wait on the
+    # tables never waits on them
     w_main, w_rem = batch_inv(FQ, e_full_m), batch_inv(FQ, e_full_r)
     return ValidityStatements(
         a_main=a_main, b_main=b_main, w_main=w_main,

@@ -263,31 +263,43 @@ def inv(spec: FieldSpec, a):
     return pow_const(spec, a, spec.modulus - 2)
 
 
-def batch_inv(spec: FieldSpec, a):
-    """Montgomery batch inversion of a flat (n, 4) array: one inv + 3n muls.
+#: most lanes of `batch_inv`'s blocked layout (a power of two)
+_INV_LANES = 1 << 13
 
-    jit'd: the two lax.scans otherwise re-trace (and re-compile) on every
-    eager call because their body closures are fresh function objects."""
+
+def batch_inv(spec: FieldSpec, a):
+    """Montgomery batch inversion of a flat (n, 4) array, lane-blocked.
+
+    The input, padded with ones to m * L elements, is read as m rows of
+    L independent lanes (element i at row i // L, lane i % L), with
+    L = min(next_pow2(n), `_INV_LANES`).  A scan over the rows keeps
+    each lane's exclusive prefix products, one vectorised Fermat `inv`
+    inverts the L lane totals, and a reverse scan gives every inverse:
+    3n muls plus one (L, 4) inversion, on a dependent chain of m steps
+    instead of n.  Every input must be nonzero; inverses are unique, so
+    the result is the same for any block shape."""
     n = a.shape[0]
     if n == 0:
         return a
+    lanes = min(1 << (n - 1).bit_length(), _INV_LANES)
+    rows = -(-n // lanes)
     one = jnp.asarray(spec.one)
+    pad = jnp.broadcast_to(one, (rows * lanes - n, NLIMB))
+    x = jnp.concatenate([a, pad]).reshape(rows, lanes, NLIMB)
 
-    def fwd(carry, x):
-        nxt = mont_mul(spec, carry, x)
-        return nxt, carry  # prefix product *excluding* x
+    def fwd(carry, row):
+        return mont_mul(spec, carry, row), carry  # prefix *excluding* row
 
-    total, prefix_ex = jax.lax.scan(fwd, one, a)
-    inv_total = inv(spec, total)
+    start = jnp.broadcast_to(one, (lanes, NLIMB))
+    total, prefix_ex = jax.lax.scan(fwd, start, x)
 
     def bwd(carry, xs):
-        x, pre = xs
-        out = mont_mul(spec, carry, pre)
-        nxt = mont_mul(spec, carry, x)
-        return nxt, out
+        row, pre = xs
+        return mont_mul(spec, carry, row), mont_mul(spec, carry, pre)
 
-    _, outs = jax.lax.scan(bwd, inv_total, (a, prefix_ex), reverse=True)
-    return outs
+    _, outs = jax.lax.scan(bwd, inv(spec, total), (x, prefix_ex),
+                           reverse=True)
+    return outs.reshape(-1, NLIMB)[:n]
 
 
 def to_mont(spec: FieldSpec, x_limbs):
@@ -309,7 +321,11 @@ from repro.core import execache as _execache
 mont_mul = _execache.wrap("f_mont_mul", mont_mul, static_argnums=(0,))
 add = _execache.wrap("f_add", add, static_argnums=(0,))
 sub = _execache.wrap("f_sub", sub, static_argnums=(0,))
-batch_inv = _execache.wrap("f_batch_inv", batch_inv, static_argnums=(0,))
+# batch_inv's cache name changes with its algorithm: the key holds no
+# fingerprint of the code, so a disk cache kept from an earlier
+# algorithm would otherwise replay it
+batch_inv = _execache.wrap("f_batch_inv_blocked", batch_inv,
+                           static_argnums=(0,))
 to_mont = _execache.wrap("f_to_mont", to_mont, static_argnums=(0,))
 from_mont = _execache.wrap("f_from_mont", from_mont, static_argnums=(0,))
 

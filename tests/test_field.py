@@ -11,6 +11,7 @@ from repro.field import (
     encode_ints, decode, encode_int, from_mont, to_mont, ints_to_limbs,
     limbs_to_ints,
 )
+from repro.field import modarith
 
 SPECS = [FQ, FP]
 
@@ -72,14 +73,41 @@ def test_inv_and_pow(spec):
     assert all(int(v) == 1 for v in dec(spec, p0))
 
 
-@pytest.mark.parametrize("spec", SPECS, ids=lambda s: s.name)
-def test_batch_inv(spec):
-    rng = np.random.default_rng(2)
-    xs = [int(rng.integers(1, spec.modulus)) for _ in range(33)]
-    a = enc(spec, xs)
-    b = batch_inv(spec, a)
+def _py_batch_inv(xs, m):
+    """Montgomery's trick on python ints: the reference `batch_inv` must
+    match, independent of its block layout."""
+    prefix, acc = [], 1
+    for x in xs:
+        prefix.append(acc)
+        acc = acc * x % m
+    inv_acc, out = pow(acc, m - 2, m), [0] * len(xs)
+    for i in reversed(range(len(xs))):
+        out[i] = inv_acc * prefix[i] % m
+        inv_acc = inv_acc * xs[i] % m
+    return out
+
+
+_LANES = modarith._INV_LANES
+# one element; one partial row; a power of two above the lane count
+# (several full rows); padded rows; and past 2^15 (FQ only: each size
+# compiles its own program)
+_BATCH_INV_CASES = ([(s, n) for s in SPECS for n in (1, 33, 2 * _LANES)]
+                    + [(FQ, 3 * _LANES + 5), (FQ, 5 * _LANES + 1)])
+
+
+@pytest.mark.parametrize("spec,n", _BATCH_INV_CASES,
+                         ids=lambda v: getattr(v, "name", str(v)))
+def test_batch_inv(spec, n):
+    assert 5 * _LANES + 1 > 1 << 15
     m = spec.modulus
-    assert [int(v) for v in dec(spec, b)] == [pow(x, m - 2, m) for x in xs]
+    rng = np.random.default_rng(n)
+    xs = rng.integers(1, m, size=n, dtype=np.uint64).tolist()
+    b = np.asarray(batch_inv(spec, enc(spec, xs)))
+    want = [pow(x, m - 2, m) for x in xs]
+    assert _py_batch_inv(xs, m) == want
+    # bit for bit, in Montgomery limbs
+    np.testing.assert_array_equal(
+        b, encode_ints(spec, np.array(want, dtype=object)))
 
 
 def test_limb_roundtrip_multidim():

@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from repro.field import FQ
+from repro.field import FQ, modarith
 from repro.core import zkrelu
 from repro.core.mle import hexpand_point
 from repro.core.transcript import Transcript
@@ -167,3 +167,43 @@ def test_bits_roundtrip():
     bu = zkrelu.bits_unsigned(u, 7)
     rec_u = sum(bu[:, j].astype(np.int64) << j for j in range(7))
     assert (rec_u == u).all()
+
+
+def test_statement_weights_invert_e():
+    """prove_statements' H-basis weights are 1/e, element by element, for
+    e = e_relu (x) e_bit (and e_relu (x) e_bit_r), at a geometry where
+    the main statement's inversion spans several rows of lanes."""
+    from repro.field import mont_mul
+    from repro.core.mle import expand_point
+
+    ds, qb, rb = 512, 16, 8
+    assert 2 * ds * qb > modarith._INV_LANES
+    rng = np.random.default_rng(11)
+    keys = zkrelu.make_validity_keys(ds, qb, rb)
+    bits = zkrelu.build_aux_bits(
+        rng.integers(0, 1 << (qb - 1), size=ds),
+        rng.integers(-(1 << (qb - 1)), 1 << (qb - 1), size=ds),
+        rng.integers(0, 2, size=ds), rng.integers(0, 1 << rb, size=ds),
+        rng.integers(0, 1 << rb, size=ds), qb, rb)
+    blinds = zkrelu.ValidityBlinds(*(int(rng.integers(0, 1 << 60))
+                                     for _ in range(4)))
+    tp, tv = Transcript(b"weights"), Transcript(b"weights")
+    n_vars = ds.bit_length()
+    u_relu = tp.challenge_ints(b"urelu", Q_MOD, n_vars)
+    tv.challenge_ints(b"urelu", Q_MOD, n_vars)
+    st = zkrelu.prove_statements(keys, bits, blinds, u_relu, 1, 2, 3, tp)
+
+    # the same challenge draws, in prove_statements' order
+    tv.challenge_int(b"zkrelu/k", Q_MOD)
+    u_bit = tv.challenge_ints(b"zkrelu/ubit", Q_MOD, (qb - 1).bit_length())
+    tv.challenge_int(b"zkrelu/z", Q_MOD)
+    u_bit_r = tv.challenge_ints(b"zkrelu/ubitr", Q_MOD,
+                                (rb - 1).bit_length())
+    e_relu = expand_point(u_relu)
+    one = np.asarray(FQ.one)
+    for w, u, width in ((st.w_main, u_bit, qb), (st.w_rem, u_bit_r, rb)):
+        e = mont_mul(FQ, e_relu[:, None, :],
+                     expand_point(u)[:width][None, :, :]).reshape(-1, 4)
+        assert w.shape == e.shape == (2 * ds * width, 4)
+        prod = np.asarray(mont_mul(FQ, w, e))
+        assert (prod == one).all(), np.argwhere((prod != one).any(-1))[:5]
